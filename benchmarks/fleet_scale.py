@@ -74,6 +74,7 @@ import os
 import time
 import zlib
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CodeParams
 from repro.fleet import SCENARIOS, ClusterEnsemble, FleetSimulator, \
     ReadTrace, Scenario, generate_trace, make_policy, mitigated, simulate
@@ -398,6 +399,7 @@ def main() -> None:
                     help="override K for the ensemble rows (0 = per-row "
                          "defaults)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         os.environ["BENCH_QUICK"] = "1"
     print("name,us_per_call,derived")

@@ -269,6 +269,8 @@ def _parse_args(argv=None) -> argparse.Namespace:
 
 def main(argv=None) -> None:
     args = _parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # the flags are sugar over the env vars every module already reads
     if args.quick:
         os.environ["BENCH_QUICK"] = "1"

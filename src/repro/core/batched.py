@@ -468,7 +468,11 @@ def _fr_msr_closed_form_batch(direct: np.ndarray, params: CodeParams,
     m = d - k + 1
     order = np.argsort(direct, axis=1, kind="stable")
     csort = np.take_along_axis(direct, order, axis=1)
-    denom = csort[:, :m].sum(axis=1)
+    # left to right, as the scalar oracle sums (ndarray.sum goes pairwise
+    # from m >= 8 on)
+    denom = np.zeros(direct.shape[0])
+    for i in range(m):
+        denom = denom + csort[:, i]
     rank = np.arange(d)[None, :]
     beta_sorted = np.where(rank < m, csort, csort[:, m - 1:m]) * M / (k * denom[:, None])
     betas = np.empty_like(beta_sorted)

@@ -16,10 +16,10 @@ executable per (batch, d, k) shape:
 * per-lane Python state (the NumPy engine's mode machines) becomes masked
   lanes: every lane issues every oracle query, with ``jnp.where`` keeping
   non-participating lanes at a benign t=1.0 probe whose answer is ignored;
-* float64 is enabled via the scoped ``jax.experimental.enable_x64``
-  context around each planner call (never the global flag, so importing
-  this module cannot perturb float32-default JAX code elsewhere in the
-  process).
+* float64 is enabled via the scoped ``jax.enable_x64`` context around
+  each planner call (never the global flag, so importing this module
+  cannot perturb float32-default JAX code elsewhere in the process).  The
+  TPU has no float64 units; XLA emulates the float64 ops there.
 
 The NumPy planners remain the oracle: decision sequences (incumbent
 pruning, duplicate skips, pivot accept order, tie-breaks) are replicated
@@ -75,8 +75,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental import enable_x64
+from jax import enable_x64, lax
 
 from .batched import BatchPlanResult, _star_parents
 from .ftr import (EVAL_ITERS as _EVAL_ITERS, FINAL_ITERS as _FINAL_ITERS,

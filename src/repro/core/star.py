@@ -9,6 +9,16 @@ from .regions import FeasibleRegion, heuristic_region, msr_region
 from . import lp
 
 
+def _sum_ltr(xs) -> float:
+    """Left-to-right float sum.  The built-in ``sum()`` is compensated
+    since Python 3.12; the batched engine adds naively in index order, and
+    the scalar oracles must round the same way for bitwise parity."""
+    acc = 0.0
+    for x in xs:
+        acc += x
+    return acc
+
+
 def _star_parent(d: int) -> Dict[int, int]:
     return {i: 0 for i in range(1, d + 1)}
 
@@ -45,7 +55,7 @@ def fr_closed_form_msr(caps: List[float], params: CodeParams) -> List[float]:
     d, k, M = params.d, params.k, params.M
     order = sorted(range(d), key=lambda i: caps[i])
     m = d - k + 1
-    denom = sum(caps[order[i]] for i in range(m))
+    denom = _sum_ltr(caps[order[i]] for i in range(m))
     betas = [0.0] * d
     if denom <= 0:
         raise ZeroDivisionError("the d-k+1 slowest links have zero capacity")
@@ -121,7 +131,7 @@ def plan_shah(net: OverlayNetwork, params: CodeParams,
     # bisection on t: achievable iff sum_i min(t*c_i, beta_max) >= gamma
     lo, hi = 0.0, 1.0
     def tot(t: float) -> float:
-        return sum(min(t * c, beta_max) for c in caps)
+        return _sum_ltr(min(t * c, beta_max) for c in caps)
     while tot(hi) < gamma:
         hi *= 2
         if hi > 1e18:
@@ -136,7 +146,7 @@ def plan_shah(net: OverlayNetwork, params: CodeParams,
     t = hi
     betas = [min(t * c, beta_max) for c in caps]
     # trim surplus from the slowest contributors (they set the clock)
-    surplus = sum(betas) - gamma
+    surplus = _sum_ltr(betas) - gamma
     for i in sorted(range(d), key=lambda i: caps[i]):
         if surplus <= 0:
             break

@@ -233,10 +233,9 @@ class DataPlane:
 
         ``"numpy"`` uses the field's log/antilog tables; ``"kernel"``
         routes through ``repro.kernels.gf_matmul_numpy`` (Pallas on
-        TPU, interpret mode — with a transparent warn-once reference
-        fallback — on CPU); ``"auto"`` picks the kernel only when a
-        real TPU backend is present, since interpret-mode Pallas is far
-        slower than the tables for the store's tiny matmuls.
+        TPU, interpret mode on CPU); ``"auto"`` picks the kernel only
+        when a real TPU backend is present, since interpret-mode Pallas is
+        far slower than the tables for the store's tiny matmuls.
         """
         if mode == "numpy":
             return None

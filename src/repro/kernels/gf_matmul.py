@@ -30,11 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams across JAX releases;
-# support both so the kernel (and its interpret-mode path) runs everywhere
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 _FOLD = (0, 2, 3, 4)  # x^8 == x^4 + x^3 + x^2 + 1
 
 
@@ -90,7 +85,7 @@ def gf_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.uint8),
         scratch_shapes=[pltpu.VMEM((15, bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

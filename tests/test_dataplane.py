@@ -21,8 +21,8 @@ Closed forms and invariants:
 * tracing a dataplane run never perturbs it, and the new event
   vocabulary round-trips through the Chrome converter and the report
   analyses (including the no-header ``repair_block`` fallback);
-* the GF(2^8) kernel wrapper falls back to the pure-jnp reference with
-  one warning when Pallas is unavailable (CPU-safe coding plane).
+* the GF(2^8) kernel wrapper has no fallback: a Pallas error raises out
+  of ``gf_matmul`` instead of being answered by another path.
 """
 import dataclasses
 import json
@@ -346,30 +346,25 @@ def test_chrome_and_report_round_trip(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# 8. CPU-safe kernel fallback (ISSUE 10 satellite)
+# 8. No kernel fallback
 # ---------------------------------------------------------------------------
 
-def test_gf_matmul_falls_back_to_reference_with_one_warning(monkeypatch):
+def test_gf_matmul_propagates_kernel_errors(monkeypatch):
     from repro.kernels import ops
 
     rng = np.random.default_rng(42)
     a = rng.integers(0, 256, (5, 7), dtype=np.uint8)
     b = rng.integers(0, 256, (7, 9), dtype=np.uint8)
-    want = GF8.matmul(a, b)
 
     def boom(*args, **kwargs):
-        raise RuntimeError("no pallas lowering on this host")
+        raise RuntimeError("kernel rejected by the backend's compiler")
 
     monkeypatch.setattr(ops, "_padded_call", boom)
-    monkeypatch.setitem(ops._fallback, "active", False)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        out1 = np.asarray(ops.gf_matmul(a, b))
-        out2 = np.asarray(ops.gf_matmul(a, b))   # latched: no second warn
-    np.testing.assert_array_equal(out1, want)
-    np.testing.assert_array_equal(out2, want)
-    runtime = [w for w in caught if w.category is RuntimeWarning]
-    assert len(runtime) == 1, "fallback must warn exactly once"
-    assert "falling back" in str(runtime[0].message)
-    # reset the process-wide latch so later tests take the kernel path
-    monkeypatch.setitem(ops._fallback, "active", False)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        for _ in range(2):          # every call raises: nothing latches
+            with pytest.raises(RuntimeError, match="kernel rejected"):
+                ops.gf_matmul(a, b)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(np.asarray(ops.gf_matmul(a, b)),
+                                  GF8.matmul(a, b))

@@ -72,3 +72,16 @@ def test_zero_padding_soundness():
     """Padding with zeros must not perturb the visible result region."""
     a, b = _rand(100, 200, 50)
     np.testing.assert_array_equal(np.asarray(gf_matmul(a, b)), GF8.matmul(a, b))
+
+
+def test_padded_result_is_read_after_the_kernel_writes_it():
+    """Regression: with the slice compiled into the same program as the
+    interpreted kernel, the CPU backend sometimes returned a few entries of
+    the slice before the kernel had written them.  Many fresh calls at one
+    unaligned shape catch that."""
+    rng = np.random.default_rng(7)
+    for _ in range(30):
+        a = rng.integers(0, 256, (17, 33), dtype=np.uint8)
+        b = rng.integers(0, 256, (33, 9), dtype=np.uint8)
+        np.testing.assert_array_equal(np.asarray(gf_matmul(a, b)),
+                                      GF8.matmul(a, b))
