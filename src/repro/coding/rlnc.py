@@ -13,6 +13,8 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs.spans import span
+
 from .gf import GF, GF8
 
 
@@ -31,8 +33,10 @@ class CodedBlocks:
         return self.vectors.shape[0]
 
     def concat(self, other: "CodedBlocks") -> "CodedBlocks":
-        return CodedBlocks(np.concatenate([self.vectors, other.vectors]),
-                           np.concatenate([self.payload, other.payload]))
+        parts = (self.vectors, other.vectors, self.payload, other.payload)
+        with span("store.concat", bytes=sum(a.nbytes for a in parts)):
+            return CodedBlocks(np.concatenate(parts[:2]),
+                               np.concatenate(parts[2:]))
 
 
 class RLNC:

@@ -47,7 +47,6 @@ DeprecationWarning per name per process) so external code keeps working.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import importlib.util
 import warnings
@@ -56,12 +55,14 @@ from typing import (Callable, Dict, Iterator, List, Mapping, Optional,
 
 import numpy as np
 
+from repro.obs.spans import span
+
 from .params import CodeParams, OverlayNetwork, RepairPlan
 from .star import plan_fr, plan_shah, plan_star
 from .tree import plan_tr
 from .ftr import plan_ftr
 from .rctree import plan_rctree
-from .batched import (BatchPlanResult, caps_tensor, plan_fr_batch,
+from .batched import (BatchPlanResult, _pstage, caps_tensor, plan_fr_batch,
                       plan_ftr_batch, plan_shah_batch, plan_star_batch,
                       plan_tr_batch, plans_from_batch)
 
@@ -287,16 +288,6 @@ def _planner_kwargs(spec: SchemeSpec, witness: str, kwargs: dict) -> dict:
     return kw
 
 
-def _pstage(profile, name: str):
-    """Stage-timing context: ``profile`` is any PlannerProfile-shaped
-    object (``stage``/``count``/``note``, see ``repro.obs.profile`` — the
-    contract is duck-typed so the planning core stays import-free of the
-    observability package), or None for the zero-overhead default."""
-    if profile is None:
-        return contextlib.nullcontext()
-    return profile.stage(name)
-
-
 def _check_engine(engine: str) -> None:
     if engine not in ENGINES:
         raise ValueError(f"unknown engine {engine!r}; expected one of "
@@ -376,35 +367,36 @@ def plan_many(nets: Union[np.ndarray, Sequence[OverlayNetwork]],
     batch; on the scalar path the original :class:`RepairPlan` objects ride
     along in ``plans`` and ``plans_from_batch`` returns them verbatim.
     """
-    _check_engine(engine)
-    spec = get_scheme(scheme)
-    is_tensor = isinstance(nets, np.ndarray)
-    if not is_tensor:
-        nets = list(nets)
-        ds = {n.d for n in nets}
-        if len(ds) > 1:
-            return _plan_ragged(nets, params, scheme, engine=engine,
-                                witness=witness, profile=profile, **kwargs)
-    kw = _planner_kwargs(spec, witness, kwargs)
-    resolved = _resolve_engine(spec, engine, "plan_many")
-    if profile is not None:
-        profile.note(scheme=spec.name,
-                     batch=int(nets.shape[0]) if is_tensor else len(nets),
-                     d=params.d, engine=resolved,
-                     fallback=engine not in ("auto", resolved))
-    if resolved in ("batched", "jax"):
-        planner = spec.batched if resolved == "batched" else spec.jax
-        caps = nets if is_tensor else caps_tensor(nets)
-        if resolved == "batched" and spec.accepts_profile \
-                and profile is not None:
-            kw["profile"] = profile
+    with span("plan_many"):
+        _check_engine(engine)
+        spec = get_scheme(scheme)
+        is_tensor = isinstance(nets, np.ndarray)
+        if not is_tensor:
+            nets = list(nets)
+            ds = {n.d for n in nets}
+            if len(ds) > 1:
+                return _plan_ragged(nets, params, scheme, engine=engine,
+                                    witness=witness, profile=profile, **kwargs)
+        kw = _planner_kwargs(spec, witness, kwargs)
+        resolved = _resolve_engine(spec, engine, "plan_many")
+        if profile is not None:
+            profile.note(scheme=spec.name,
+                         batch=int(nets.shape[0]) if is_tensor else len(nets),
+                         d=params.d, engine=resolved,
+                         fallback=engine not in ("auto", resolved))
+        if resolved in ("batched", "jax"):
+            planner = spec.batched if resolved == "batched" else spec.jax
+            caps = nets if is_tensor else caps_tensor(nets)
+            if resolved == "batched" and spec.accepts_profile \
+                    and profile is not None:
+                kw["profile"] = profile
+            with _pstage(profile, "total"):
+                return planner(caps, params, **kw)
+        net_list = ([OverlayNetwork(c.tolist()) for c in nets] if is_tensor
+                    else list(nets))
         with _pstage(profile, "total"):
-            return planner(caps, params, **kw)
-    net_list = ([OverlayNetwork(c.tolist()) for c in nets] if is_tensor
-                else list(nets))
-    with _pstage(profile, "total"):
-        plans = [spec.scalar(n, params, **kw) for n in net_list]
-    return _batch_from_plans(spec, plans, params)
+            plans = [spec.scalar(n, params, **kw) for n in net_list]
+        return _batch_from_plans(spec, plans, params)
 
 
 def _plan_ragged(nets: List[OverlayNetwork], params: CodeParams, scheme: str,

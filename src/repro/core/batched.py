@@ -36,6 +36,8 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.spans import span
+
 from . import lp
 from . import witness as wit
 from .ftr import (EVAL_ITERS as _EVAL_ITERS, FINAL_ITERS as _FINAL_ITERS,
@@ -50,17 +52,22 @@ _SPEC_LEVELS = 3            # bisection levels speculated per oracle call
 _SPEC_NN = (1 << _SPEC_LEVELS) - 1   # rows per speculated bisection tree
 
 
+@contextlib.contextmanager
 def _pstage(profile, name: str):
-    """Stage-timing context for the ``profile=`` hook (ISSUE 7).
+    """One stage of a planner: a ``repro.plan.<name>`` span on the
+    profiler's clock and, when ``profile`` is given, the stage's wall time
+    there.
 
     ``profile`` is any object with the ``repro.obs.profile.PlannerProfile``
-    contract (duck-typed — this module never imports the obs package), or
-    None for the zero-overhead default.  Timing wraps whole stages, so a
-    profiled plan is the same plan: stages only measure, never branch.
+    contract (duck-typed: this module never imports it), or None.  Stages
+    only measure, never branch, so a profiled plan is the same plan.
     """
-    if profile is None:
-        return contextlib.nullcontext()
-    return profile.stage(name)
+    with span("plan." + name):
+        if profile is None:
+            yield
+        else:
+            with profile.stage(name):
+                yield
 
 
 # ---------------------------------------------------------------------------

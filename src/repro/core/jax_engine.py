@@ -77,6 +77,8 @@ import jax
 import jax.numpy as jnp
 from jax import enable_x64, lax
 
+from repro.obs.spans import span
+
 from .batched import BatchPlanResult, _star_parents
 from .ftr import (EVAL_ITERS as _EVAL_ITERS, FINAL_ITERS as _FINAL_ITERS,
                   LOCAL_SEARCH_ALTS as _MAX_ALTS,
@@ -690,15 +692,17 @@ def _ftr_kernel(caps, x, alpha, beta_u, local_search):
 
 def plan_star_jax(caps: np.ndarray, params: CodeParams) -> BatchPlanResult:
     """Jit-compiled ``plan_star_batch``."""
-    caps = np.asarray(caps, dtype=np.float64)
-    B, _, _ = caps.shape
-    d = params.d
-    with enable_x64():
-        t, tr, be = _star_kernel(jnp.asarray(_pad_caps(caps)[:, 1:, 0]),
-                                 float(params.beta), float(params.alpha))
-        t, tr, be = (np.asarray(a)[:B] for a in (t, tr, be))
-    return BatchPlanResult("star", t, tr, be, _star_parents(B, d),
-                           engine="jax")
+    with span("plan.prep"):
+        caps = np.asarray(caps, dtype=np.float64)
+        B, _, _ = caps.shape
+        direct = _pad_caps(caps)[:, 1:, 0]
+    with span("plan.dispatch"), enable_x64():
+        out = _star_kernel(jnp.asarray(direct), float(params.beta),
+                           float(params.alpha))
+    with span("plan.fetch"):
+        t, tr, be = (np.asarray(a)[:B] for a in out)
+        return BatchPlanResult("star", t, tr, be, _star_parents(B, params.d),
+                               engine="jax")
 
 
 def plan_fr_jax(caps: np.ndarray, params: CodeParams,
@@ -707,34 +711,38 @@ def plan_fr_jax(caps: np.ndarray, params: CodeParams,
                 witness: str = "exact") -> BatchPlanResult:
     """Jit-compiled ``plan_fr_batch`` (closed form at MSR, lockstep star
     bisection + level-cut witness elsewhere)."""
-    _check_witness(witness)
-    region = _region_for(params, region)
-    caps = np.asarray(caps, dtype=np.float64)
-    B, _, _ = caps.shape
-    d = params.d
-    x = np.asarray(region.x, dtype=np.float64)
-    with enable_x64():
-        t, tr, be, lb = _fr_kernel(jnp.asarray(_pad_caps(caps)[:, 1:, 0]),
-                                   jnp.asarray(x), float(params.alpha),
-                                   float(params.M), is_msr=params.is_msr,
-                                   minimize_traffic=bool(minimize_traffic))
-        t, tr, be, lb = (np.asarray(a)[:B] for a in (t, tr, be, lb))
-    return BatchPlanResult("fr", t, tr, be, _star_parents(B, d),
-                           lower_bounds=lb, engine="jax")
+    with span("plan.prep"):
+        _check_witness(witness)
+        region = _region_for(params, region)
+        caps = np.asarray(caps, dtype=np.float64)
+        B, _, _ = caps.shape
+        direct = _pad_caps(caps)[:, 1:, 0]
+        x = np.asarray(region.x, dtype=np.float64)
+    with span("plan.dispatch"), enable_x64():
+        out = _fr_kernel(jnp.asarray(direct), jnp.asarray(x),
+                         float(params.alpha), float(params.M),
+                         is_msr=params.is_msr,
+                         minimize_traffic=bool(minimize_traffic))
+    with span("plan.fetch"):
+        t, tr, be, lb = (np.asarray(a)[:B] for a in out)
+        return BatchPlanResult("fr", t, tr, be, _star_parents(B, params.d),
+                               lower_bounds=lb, engine="jax")
 
 
 def plan_tr_jax(caps: np.ndarray, params: CodeParams) -> BatchPlanResult:
     """Jit-compiled ``plan_tr_batch`` (Algorithm 1)."""
-    caps = np.asarray(caps, dtype=np.float64)
-    B, _, _ = caps.shape
-    d = params.d
-    with enable_x64():
-        t, tr, par = _tr_kernel(jnp.asarray(_pad_caps(caps)),
-                                float(params.beta), float(params.alpha))
-        t, tr = np.asarray(t)[:B], np.asarray(tr)[:B]
-        par = np.asarray(par)[:B].astype(np.int64)
-    return BatchPlanResult("tr", t, tr, np.full((B, d), params.beta), par,
-                           engine="jax")
+    with span("plan.prep"):
+        caps = np.asarray(caps, dtype=np.float64)
+        B, _, _ = caps.shape
+        padded = _pad_caps(caps)
+    with span("plan.dispatch"), enable_x64():
+        out = _tr_kernel(jnp.asarray(padded), float(params.beta),
+                         float(params.alpha))
+    with span("plan.fetch"):
+        t, tr, par = (np.asarray(a)[:B] for a in out)
+        betas = np.full((B, params.d), params.beta)
+        return BatchPlanResult("tr", t, tr, betas, par.astype(np.int64),
+                               engine="jax")
 
 
 def plan_ftr_jax(caps: np.ndarray, params: CodeParams,
@@ -743,17 +751,18 @@ def plan_ftr_jax(caps: np.ndarray, params: CodeParams,
                  witness: str = "exact") -> BatchPlanResult:
     """Jit-compiled ``plan_ftr_batch`` (Algorithm 2 + pivot search + final
     50-iteration solve + level-cut witness)."""
-    _check_witness(witness)
-    region = _region_for(params, region)
-    caps = np.asarray(caps, dtype=np.float64)
-    B, _, _ = caps.shape
-    x = np.asarray(region.x, dtype=np.float64)
-    with enable_x64():
-        t, tr, be, par, lbs = _ftr_kernel(
-            jnp.asarray(_pad_caps(caps)), jnp.asarray(x),
-            float(params.alpha), float(params.beta),
-            local_search=bool(local_search))
-        t, tr, be, lbs = (np.asarray(a)[:B] for a in (t, tr, be, lbs))
-        par = np.asarray(par)[:B].astype(np.int64)
-    return BatchPlanResult("ftr", t, tr, be, par, lower_bounds=lbs,
-                           engine="jax")
+    with span("plan.prep"):
+        _check_witness(witness)
+        region = _region_for(params, region)
+        caps = np.asarray(caps, dtype=np.float64)
+        B, _, _ = caps.shape
+        padded = _pad_caps(caps)
+        x = np.asarray(region.x, dtype=np.float64)
+    with span("plan.dispatch"), enable_x64():
+        out = _ftr_kernel(jnp.asarray(padded), jnp.asarray(x),
+                          float(params.alpha), float(params.beta),
+                          local_search=bool(local_search))
+    with span("plan.fetch"):
+        t, tr, be, par, lbs = (np.asarray(a)[:B] for a in out)
+        return BatchPlanResult("ftr", t, tr, be, par.astype(np.int64),
+                               lower_bounds=lbs, engine="jax")

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.spans import span
+
 from .gf_matmul import gf_matmul_pallas
 from .ref import gf_matmul_ref
 
@@ -69,11 +71,27 @@ def gf_matmul(a, b, *, bm: int = 128, bn: int = 128, bk: int = 512,
     return out if out.shape == (m, n) else out[:m, :n]
 
 
-def gf_matmul_numpy(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+def _host_bytes(x) -> int:
+    """Bytes that putting ``x`` on the device as uint8 copies from the host."""
+    return 0 if isinstance(x, jax.Array) else int(np.size(x))
+
+
+def gf_matmul_numpy(a, b) -> np.ndarray:
     """Kernel-backed matmul with a numpy interface (pluggable into
     :class:`repro.coding.rlnc.RLNC` to run the coding plane through the
-    kernel end-to-end)."""
-    return np.asarray(gf_matmul(np.asarray(a, np.uint8), np.asarray(b, np.uint8)))
+    kernel end-to-end).
+
+    Spans: ``repro.gf_matmul`` around the call, and inside it
+    ``repro.gf.h2d`` (the operands' copy to the device, ``bytes`` copied
+    from the host), ``repro.gf.dispatch`` (the kernel call and the slice)
+    and ``repro.gf.d2h`` (the product's copy back, its ``bytes``)."""
+    with span("gf_matmul"):
+        with span("gf.h2d", bytes=_host_bytes(a) + _host_bytes(b)):
+            a, b = jnp.asarray(a, jnp.uint8), jnp.asarray(b, jnp.uint8)
+        with span("gf.dispatch"):
+            out = gf_matmul(a, b)
+        with span("gf.d2h", bytes=out.size):
+            return np.asarray(out)
 
 
 def gf_matmul_reference(a, b) -> jnp.ndarray:

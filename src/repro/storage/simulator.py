@@ -23,6 +23,7 @@ import numpy as np
 from repro.coding import GF, GF8, RLNC, CodedBlocks
 from repro.core import (CodeParams, RepairPlan, caps_tensor, get_scheme,
                         plan, plan_many, plans_from_batch)
+from repro.obs.spans import span
 from .capacities import CapSampler
 
 
@@ -142,7 +143,16 @@ class RlncSimulator:
         Fractional betas/flows are ceil-rounded (Section III-C).  For the
         broken RCTREE baseline, flows are the plan's fixed per-edge beta,
         which is what destroys information at interior nodes.
+
+        Spans: ``repro.execute_plan`` around the call, and
+        ``repro.store.node`` around each tree node's work, the newcomer's
+        included; a node's span holds those of its children.
         """
+        with span("execute_plan"), span("store.node"):
+            self._execute_plan(plan, failed, provider_ids)
+
+    def _execute_plan(self, plan: RepairPlan, failed: int,
+                      provider_ids: Sequence[int]) -> None:
         alpha = int(round(self.params.alpha))
         idmap = {i: pid for i, pid in enumerate(provider_ids, start=1)}
         children: Dict[int, List[int]] = {}
@@ -154,7 +164,8 @@ class RlncSimulator:
             own_quota = plan.betas[u - 1]
             recv: Optional[CodedBlocks] = None
             for ch in children.get(u, []):
-                part = produce(ch)
+                with span("store.node"):
+                    part = produce(ch)
                 recv = part if recv is None else recv.concat(part)
             send_quota = int(math.ceil(plan.flows[(u, plan.parent[u])] - 1e-9))
             own = self.rl.encode(self.nodes[idmap[u]],
@@ -175,7 +186,8 @@ class RlncSimulator:
 
         received: Optional[CodedBlocks] = None
         for r in children.get(0, []):
-            part = produce(r)
+            with span("store.node"):
+                part = produce(r)
             received = part if received is None else received.concat(part)
         assert received is not None
         self.nodes[failed] = self.rl.regenerate(received, alpha, self.np_rng)
