@@ -9,7 +9,7 @@ pairs — the compute hot-spot accelerated by ``repro.kernels.gf_matmul``.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+from typing import List, Sequence
 
 import numpy as np
 
@@ -32,11 +32,20 @@ class CodedBlocks:
     def num(self) -> int:
         return self.vectors.shape[0]
 
+    @staticmethod
+    def join(parts: Sequence["CodedBlocks"]) -> "CodedBlocks":
+        """The parts' rows in order, in new arrays; one part is returned
+        as it is.  Span ``repro.store.concat`` with the ``bytes`` copied
+        and the number of ``parts``."""
+        if len(parts) == 1:
+            return parts[0]
+        nbytes = sum(p.vectors.nbytes + p.payload.nbytes for p in parts)
+        with span("store.concat", bytes=nbytes, parts=len(parts)):
+            return CodedBlocks(np.concatenate([p.vectors for p in parts]),
+                               np.concatenate([p.payload for p in parts]))
+
     def concat(self, other: "CodedBlocks") -> "CodedBlocks":
-        parts = (self.vectors, other.vectors, self.payload, other.payload)
-        with span("store.concat", bytes=sum(a.nbytes for a in parts)):
-            return CodedBlocks(np.concatenate(parts[:2]),
-                               np.concatenate(parts[2:]))
+        return CodedBlocks.join([self, other])
 
 
 class RLNC:
@@ -63,28 +72,24 @@ class RLNC:
 
     # -- regeneration --------------------------------------------------------
 
-    def encode(self, local: CodedBlocks, num_out: int,
+    def encode(self, blocks: CodedBlocks, num_out: int,
                rng: np.random.Generator) -> CodedBlocks:
-        """Provider-side: num_out random combinations of the local blocks."""
-        R = self.field.random((num_out, local.num), rng)
-        return CodedBlocks(self._matmul(R, local.vectors),
-                           self._matmul(R, local.payload))
+        """num_out random combinations of ``blocks``: a provider's local
+        blocks, or a relaying node's joined pool."""
+        R = self.field.random((num_out, blocks.num), rng)
+        return CodedBlocks(self._matmul(R, blocks.vectors),
+                           self._matmul(R, blocks.payload))
 
     def relay(self, received: CodedBlocks, own: CodedBlocks, num_out: int,
               rng: np.random.Generator) -> CodedBlocks:
         """Interior tree node: re-encode (received ++ freshly generated own
         data) down to num_out blocks (Section V-A)."""
-        pool = received.concat(own)
-        R = self.field.random((num_out, pool.num), rng)
-        return CodedBlocks(self._matmul(R, pool.vectors),
-                           self._matmul(R, pool.payload))
+        return self.encode(CodedBlocks.join([received, own]), num_out, rng)
 
     def regenerate(self, received: CodedBlocks, alpha: int,
                    rng: np.random.Generator) -> CodedBlocks:
         """Newcomer: store alpha random combinations of everything received."""
-        R = self.field.random((alpha, received.num), rng)
-        return CodedBlocks(self._matmul(R, received.vectors),
-                           self._matmul(R, received.payload))
+        return self.encode(received, alpha, rng)
 
     # -- reconstruction --------------------------------------------------------
 

@@ -144,9 +144,15 @@ class RlncSimulator:
         broken RCTREE baseline, flows are the plan's fixed per-edge beta,
         which is what destroys information at interior nodes.
 
-        Spans: ``repro.execute_plan`` around the call, and
+        A node's pool is a list of parts, its children's then its own,
+        joined only where a GF matmul reads a pool of several parts: at a
+        relaying node and at the newcomer.  A pool within its edge flow
+        goes up as it is.
+
+        Spans: ``repro.execute_plan`` around the call,
         ``repro.store.node`` around each tree node's work, the newcomer's
-        included; a node's span holds those of its children.
+        included (a node's span holds those of its children), and
+        ``repro.store.concat`` around each join.
         """
         with span("execute_plan"), span("store.node"):
             self._execute_plan(plan, failed, provider_ids)
@@ -159,38 +165,36 @@ class RlncSimulator:
         for u, p in plan.parent.items():
             children.setdefault(p, []).append(u)
 
-        def produce(u: int) -> CodedBlocks:
-            """Blocks node u sends to its tree parent."""
-            own_quota = plan.betas[u - 1]
-            recv: Optional[CodedBlocks] = None
+        def produce(u: int) -> List[CodedBlocks]:
+            """Blocks node u sends to its tree parent, as parts in order:
+            its children's (in ``plan.parent`` order), then its own."""
+            pool: List[CodedBlocks] = []
             for ch in children.get(u, []):
                 with span("store.node"):
-                    part = produce(ch)
-                recv = part if recv is None else recv.concat(part)
+                    pool += produce(ch)
             send_quota = int(math.ceil(plan.flows[(u, plan.parent[u])] - 1e-9))
             own = self.rl.encode(self.nodes[idmap[u]],
-                                 int(math.ceil(own_quota - 1e-9)), self.np_rng)
-            if recv is None:
-                out = own
-            else:
-                pool = recv.concat(own)
-                if pool.num > send_quota:
-                    out = self.rl.relay(recv, own, send_quota, self.np_rng)
-                else:
-                    out = pool
-            # cap at the plan's edge flow (RCTREE keeps this below alpha)
-            if out.num > send_quota:
-                out = CodedBlocks(out.vectors[:send_quota],
-                                  out.payload[:send_quota])
-            return out
+                                 int(math.ceil(plan.betas[u - 1] - 1e-9)),
+                                 self.np_rng)
+            if not pool:
+                # a leaf: cap at the plan's edge flow (RCTREE keeps this
+                # below alpha)
+                return [CodedBlocks(own.vectors[:send_quota],
+                                    own.payload[:send_quota])]
+            pool.append(own)
+            if sum(p.num for p in pool) <= send_quota:
+                return pool                # forwarded unjoined, within the flow
+            # relay: recode the pool, joined once, down to the edge flow
+            return [self.rl.encode(CodedBlocks.join(pool), send_quota,
+                                   self.np_rng)]
 
-        received: Optional[CodedBlocks] = None
+        received: List[CodedBlocks] = []
         for r in children.get(0, []):
             with span("store.node"):
-                part = produce(r)
-            received = part if received is None else received.concat(part)
-        assert received is not None
-        self.nodes[failed] = self.rl.regenerate(received, alpha, self.np_rng)
+                received += produce(r)
+        assert received
+        self.nodes[failed] = self.rl.regenerate(CodedBlocks.join(received),
+                                                alpha, self.np_rng)
 
     def _sample_round(self, sampler: CapSampler,
                       failed: Optional[int] = None):

@@ -135,10 +135,11 @@ def test_copy_bytes_are_the_operands(traced):
     # operand is already on the device
     assert h2d == want + [a.nbytes + b.nbytes, b.nbytes]
     assert d2h == [m * n for m, _, n in shapes] + [3 * 40] * 2
-    # a concat reports the bytes of the arrays it joins: rows of an M = 4
-    # byte coding vector and a 16-byte payload
-    concat = [s.args["bytes"] for s in _named(trace, "repro.store.concat")]
-    assert concat and all(b > 0 and b % (4 + 16) == 0 for b in concat)
+    # a concat reports the bytes of the arrays it joins (rows of an M = 4
+    # byte coding vector and a 16-byte payload) and how many parts it joins
+    concat = [s.args for s in _named(trace, "repro.store.concat")]
+    assert concat and all(a["bytes"] > 0 and a["bytes"] % (4 + 16) == 0
+                          and a["parts"] >= 2 for a in concat)
     args = cb_spans.span_args(trace)
     assert args["repro.gf.h2d"]["bytes"] == sum(h2d)
     assert args["repro.gf.d2h"]["bytes"] == sum(d2h)
