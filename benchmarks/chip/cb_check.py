@@ -1,24 +1,31 @@
 """The comparison that decides ``correct``: what the window produced against
 the benchmark's own reference.
 
-- Plans: a sample, drawn from the seed, of the distinct plans the window's
-  ``plan_many`` calls returned, each against the plain reference planner in
-  float64 (``cb_planref``).  ``plan_gap`` is the widest relative gap
+- Plans: a sample, drawn from the seed, of the distinct plans that each
+  worker's ``plan_many`` calls returned in the window (workers replay the
+  same draws, and each worker's plans count apart), each against the
+  plain reference planner in float64 (``cb_planref``).  ``plan_gap`` is
+  the widest relative gap
   |a - b| / (1 + |b|) over times, traffic, betas and lower bounds, where a
   plan on another tree, or with another set of finite values, counts as a
-  gap of 1.  ``invalid_plans`` counts, over every distinct plan of the
-  window, those that fail against their own overlay (not a tree rooted at
+  gap of 1.  ``invalid_plans`` counts, over every distinct plan of every
+  worker, those that fail against their own overlay (not a tree rooted at
   the newcomer, MDS condition sigma_1(beta) >= M/k broken, time
   understated, traffic not the sum of the flows).
 - Kernel: ``kernel_bytes_wrong`` counts the bytes, in columns drawn from the
   seed, that a GF(2^8) matmul of the window returned and the table
   arithmetic of ``cb_gf`` does not give for the same operands.
-- Store, after the window: ``store_rows_bad`` counts stored rows whose
-  payload is not their coding vector times the block group (made again from
-  the seed), checked on every byte by GF sums of columns, and every row of
-  a node that is missing; ``undecodable_subsets`` counts, for every node the
-  window repaired, one k-node subset drawn from the seed that holds it, whose
-  coding vectors have rank below M.
+- Store, after the window, in every worker's store: ``store_rows_bad``
+  counts stored rows whose payload is not their coding vector times the
+  worker's block group (made again from the seed), checked on every byte by
+  GF sums of columns, and every row of a node that is missing;
+  ``subset_rank_deficit`` is the largest M - rank of the coding vectors of
+  a k-node subset: one subset of the worker's store, drawn from the seed,
+  for every node a worker repaired in the window, holding that node; a
+  missing node adds no row.  A random linear code over GF(2^8) leaves a
+  k-subset a row short by chance (about 1 subset in 255, the encode
+  alone); a repair that loses a helper's contribution, or half its rows,
+  leaves it tens of rows short.
 
 Each limit sits between the readings it was set from (``PERF.md``).
 """
@@ -34,13 +41,15 @@ import cb_planref
 from cb_traffic import Traffic, data_file
 
 #: plan_gap: sound runs of the program read at most ~1e-14 and the float32
-#: control at least ~1.7e-8 (PERF.md); the counts are exact comparisons
+#: control at least ~1.7e-8; subset_rank_deficit: sound runs read at most 1
+#: row and the faults it catches 128 or more (PERF.md); the counts are exact
+#: comparisons
 LIMITS = {
     "plan_gap": 1e-10,
     "invalid_plans": 0,
     "kernel_bytes_wrong": 0,
     "store_rows_bad": 0,
-    "undecodable_subsets": 0,
+    "subset_rank_deficit": 16,
 }
 #: distinct plans of each scheme compared with the reference
 CHECK_PLANS = 24
@@ -93,8 +102,10 @@ def plan_numbers(traffic: Traffic, seed: int) -> Tuple[List[Number], int]:
     seen = set()
     invalid = 0
     for ri, (scheme, caps, res) in enumerate(traffic.plan_records):
+        worker = traffic.worker_of["plan_records"][ri]
         for b in range(caps.shape[0]):
-            key = (scheme, caps[b].tobytes())
+            # every worker replays the same draws: each plans them anew
+            key = (worker, scheme, caps[b].tobytes())
             if key in seen:
                 continue
             seen.add(key)
@@ -147,36 +158,40 @@ def store_numbers(traffic: Traffic, seed: int) -> Tuple[List[Number], int]:
     n, k, alpha, M, cell = (cfg["n"], cfg["k"], cfg["alpha"], cfg["M"],
                             cfg["cell_bytes"])
     rng = np.random.default_rng([seed, 6])
-    file = data_file(seed, M, cell)
     masks = [None] + [cb_gf.column_mask(rng, cell)
                       for _ in range(STORE_MASKS)]
-    sums = [cb_gf.row_sums(file, m) for m in masks]
-    del file
-    nodes = traffic.store.nodes
     bad = 0
-    for i in range(n):
-        node = nodes.get(i)
-        if node is None or node.vectors.shape != (alpha, M) or \
-                node.payload.shape != (alpha, cell):
-            bad += alpha
-            continue
-        row_bad = np.zeros(alpha, bool)
-        for m, s in zip(masks, sums):
-            row_bad |= cb_gf.row_sums(node.payload, m) != \
-                cb_gf.matmul(node.vectors, s[:, None])[:, 0]
-        bad += int(row_bad.sum())
-    undecodable = 0
-    for node in sorted(set(traffic.repaired)):
+    for worker in traffic.workers:
+        file = data_file(seed, M, cell, worker.index)
+        sums = [cb_gf.row_sums(file, m) for m in masks]
+        del file
+        nodes = worker.store.nodes
+        for i in range(n):
+            node = nodes.get(i)
+            if node is None or node.vectors.shape != (alpha, M) or \
+                    node.payload.shape != (alpha, cell):
+                bad += alpha
+                continue
+            row_bad = np.zeros(alpha, bool)
+            for m, s in zip(masks, sums):
+                row_bad |= cb_gf.row_sums(node.payload, m) != \
+                    cb_gf.matmul(node.vectors, s[:, None])[:, 0]
+            bad += int(row_bad.sum())
+    deficit, short = 0, 0
+    for w, node in sorted(set(zip(traffic.worker_of["repaired"],
+                                  traffic.repaired))):
+        nodes = traffic.workers[w].store.nodes
         others = [i for i in range(n) if i != node]
         subset = [node] + [int(x) for x in
                            rng.choice(others, k - 1, replace=False)]
-        got = [nodes.get(i) for i in subset]
-        if any(g is None for g in got) or cb_gf.rank(
-                np.concatenate([g.vectors for g in got])) < M:
-            undecodable += 1
+        rows = [nodes[i].vectors for i in subset
+                if i in nodes and nodes[i].vectors.shape[1:] == (M,)]
+        gap = M - (cb_gf.rank(np.concatenate(rows)) if rows else 0)
+        deficit = max(deficit, gap)
+        short += gap > LIMITS["subset_rank_deficit"]
     return ([("store_rows_bad", bad, LIMITS["store_rows_bad"]),
-             ("undecodable_subsets", undecodable,
-              LIMITS["undecodable_subsets"])], bad + undecodable)
+             ("subset_rank_deficit", deficit,
+              LIMITS["subset_rank_deficit"])], bad + short)
 
 
 def check(traffic: Traffic, seed: int) -> Tuple[List[Number], int]:

@@ -16,7 +16,9 @@ import os
 import shutil
 import sys
 import tempfile
+import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -166,29 +168,59 @@ def import_program() -> None:
 
 
 def measure(traffic, seconds: float, trace_dir: Optional[str]):
-    """Steps until ``seconds`` have passed; the window ends with the step
-    that ends after them.  With ``trace_dir`` the profiler traces the first
-    ``TRACE_SECONDS`` of it, inside a ``bench.window`` span.  Returns the
-    window's length and start."""
+    """Steps until ``seconds`` have passed; each worker then ends the step
+    it is in, and the window ends when the last one stops.  One worker
+    steps in this thread, several each in a thread of its own.
+
+    With ``trace_dir`` the profiler traces the window's first steps inside
+    a ``bench.window`` span: each worker ends its traced part with the step
+    it is in once ``TRACE_SECONDS`` have passed, and waits there until the
+    last has ended its own; then the records are copied and the profiler
+    stops.  So the trace holds whole steps, and the copy holds them all.
+    Returns the window's length and start."""
     import jax
-    traced = None
+    tracing = threading.Condition()
+    in_trace = set()                   # workers still in the traced part
     if trace_dir is not None:
         jax.profiler.start_trace(trace_dir)
-        traced = jax.profiler.TraceAnnotation("bench.window")
-        traced.__enter__()
+        window = jax.profiler.TraceAnnotation("bench.window")
+        window.__enter__()
+        in_trace = {w.index for w in traffic.workers}
+
+    def leave_trace(worker) -> None:
+        with tracing:
+            if worker.index not in in_trace:
+                return
+            in_trace.discard(worker.index)
+            if not in_trace:
+                window.__exit__(None, None, None)
+                traffic.mark_traced()
+                jax.profiler.stop_trace()
+                tracing.notify_all()
+            while in_trace:
+                tracing.wait()
+
+    def loop(worker) -> float:
+        try:
+            while True:
+                traffic.step(worker)
+                elapsed = time.perf_counter() - t0
+                if elapsed >= min(TRACE_SECONDS, seconds):
+                    leave_trace(worker)
+                if elapsed >= seconds:
+                    return elapsed
+        finally:
+            leave_trace(worker)        # a worker that fails holds no other
+
     traffic.recording = True
     t0 = time.perf_counter()
-    while True:
-        traffic.step()
-        elapsed = time.perf_counter() - t0
-        if traced is not None and (elapsed >= TRACE_SECONDS
-                                   or elapsed >= seconds):
-            traced.__exit__(None, None, None)
-            jax.profiler.stop_trace()
-            traced = None
-            traffic.mark_traced()
-        if elapsed >= seconds:
-            break
+    if len(traffic.workers) == 1:
+        elapsed = loop(traffic.workers[0])
+    else:
+        with ThreadPoolExecutor(len(traffic.workers),
+                                thread_name_prefix="bench.worker") as pool:
+            futures = [pool.submit(loop, w) for w in traffic.workers]
+            elapsed = max([f.result() for f in futures])
     traffic.recording = False
     return elapsed, t0
 
@@ -216,7 +248,10 @@ def run_on(args, bench, cell, config, mix, devices, t_process: float,
 
     dev = devices[0]
     clock = CompileClock()
-    traffic = Traffic(config, mix, args.seed, program=program)
+    try:
+        traffic = Traffic(config, mix, args.seed, program=program)
+    except ValueError as e:            # a mix or configuration it cannot run
+        raise Refused(str(e))
     traffic.setup(say)
     c0 = clock.counts()
     say("setup", part="compiles", backend_compiles=c0[0],
@@ -227,8 +262,11 @@ def run_on(args, bench, cell, config, mix, devices, t_process: float,
         setup_s = t_window - t_process
         c1 = clock.counts()
         in_window = [b - a for a, b in zip(c0, c1)]
-        say("window", seconds=window_s, steps=traffic.steps,
-            repairs=traffic.repairs, plan_calls=len(traffic.plan_calls),
+        by_worker = ",".join(map(str, traffic.repairs_by_worker()))
+        say("window", seconds=window_s, workers=len(traffic.workers),
+            steps=traffic.steps, repairs=traffic.repairs,
+            repairs_by_worker=by_worker,
+            plan_calls=len(traffic.plan_calls),
             plans=traffic.plans_returned(),
             gf_calls=len(traffic.mm_shapes),
             gf_shapes=len(set(traffic.mm_shapes)),

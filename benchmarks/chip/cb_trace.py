@@ -5,7 +5,8 @@
 the trace; ``reduce`` turns them into the busy time of the device inside
 the measured window (the union of operation intervals), the device time of
 each operation and program by name, and the idle gaps, each attributed to
-the innermost host span that was open in its middle.
+the innermost host span that was open in its middle (with several workers,
+the one of them that started last).
 """
 from __future__ import annotations
 
@@ -144,8 +145,10 @@ def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
 
 
 class _OpenSpans:
-    """The innermost host span open at increasing times.  The benchmark's
-    spans come from one thread, so they nest, and a stack holds them."""
+    """The innermost host span open at increasing times.  One worker's
+    spans come from one thread, so they nest, and a stack holds them.  With
+    several workers the threads' spans overlap, and this is the span that
+    started last among those still open."""
 
     def __init__(self, spans: List[Event]):
         self.spans = sorted(spans, key=lambda e: (e.start_ns, -e.dur_ns))

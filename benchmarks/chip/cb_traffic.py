@@ -1,8 +1,8 @@
 """The one generator of the benchmark's traffic, driven by a mix's data file.
 
-A mix (``traffic/<name>.json``) is a closed loop of steps with one worker.
-Each step plans a batch of overlays with every scheme the mix names through
-the program's ``plan_many(..., engine="jax")``; a mix with ``"repair":
+A mix (``traffic/<name>.json``) is a closed loop of steps.  Each step
+plans a batch of overlays with every scheme the mix names through the
+program's ``plan_many(..., engine="jax")``; a mix with ``"repair":
 true`` then runs the plan on the coded store, with the failed node's
 fragment erased first.  Parameters:
 
@@ -12,7 +12,13 @@ fragment erased first.  Parameters:
   a number R fixes R draws (overlay, failed node, helpers) from
   ``DRAW_SEED``, which the run's seed puts in another order and replays as
   often as the window needs, so every seed warms and runs the same shapes;
-- ``repair``: whether each step runs its plan on the store.
+- ``repair``: whether each step runs its plan on the store;
+- ``workers`` (optional, 1 to ``MAX_WORKERS``, default 1; repair mixes
+  only): W closed-loop workers, each a thread with a block group and store
+  of its own, replaying the same R draws in its own order, as a DataNode
+  runs several block-group reconstructions at once (HDFS's
+  ``dfs.datanode.ec.reconstruction.threads``, 8 by default).  Worker 0 is
+  the one worker of ``workers`` 1: the same seeds, draws and shapes.
 
 Overlays are the paper's evaluation setting (Section VI): every directed
 link i.i.d. U[lo, hi], the configuration's ``capacity_lo``/``capacity_hi``.
@@ -20,8 +26,11 @@ link i.i.d. U[lo, hi], the configuration's ``capacity_lo``/``capacity_hi``.
 from __future__ import annotations
 
 import copy
+import dataclasses
+import functools
+import threading
 import time
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -33,6 +42,11 @@ Shape = Tuple[int, int, int]
 DRAW_SEED = 20160517
 #: columns of every GF product of the window kept for the output check
 CHECK_COLUMNS = 64
+#: the most workers a mix may run at once
+MAX_WORKERS = 16
+#: the window's records, each entry tagged with the worker that made it
+RECORDS = ("plan_calls", "plan_records", "exec_s", "exec_mm_s",
+           "mm_shapes", "mm_samples", "repaired")
 
 
 def overlays(rng: np.random.Generator, batch: int, d: int, lo: float,
@@ -44,10 +58,50 @@ def overlays(rng: np.random.Generator, batch: int, d: int, lo: float,
     return caps
 
 
-def data_file(seed: int, rows: int, cell: int) -> np.ndarray:
-    """The stored block group, rows x cell random bytes from the seed."""
-    rng = np.random.default_rng([seed, 2])
+def _stream(seed: int, key: int, worker: int) -> list:
+    """The seed of one of a worker's random streams: worker 0 keeps the
+    streams of a run with one worker."""
+    return [seed, key] if worker == 0 else [seed, key, worker]
+
+
+def data_file(seed: int, rows: int, cell: int, worker: int = 0
+              ) -> np.ndarray:
+    """A worker's block group, rows x cell random bytes from the seed."""
+    rng = np.random.default_rng(_stream(seed, 2, worker))
     return np.frombuffer(rng.bytes(rows * cell), np.uint8).reshape(rows, cell)
+
+
+def store_seed(seed: int, worker: int) -> int:
+    """The seed of a worker's store (its coding coefficients), derived from
+    its block group's stream."""
+    if worker == 0:
+        return seed
+    return int(np.random.SeedSequence(_stream(seed, 2, worker))
+               .generate_state(1, np.uint64)[0])
+
+
+def workers_of(mix: dict) -> int:
+    """The mix's ``workers``; ValueError outside 1..MAX_WORKERS."""
+    w = mix.get("workers", 1)
+    if type(w) is not int or not 1 <= w <= MAX_WORKERS:
+        raise ValueError(f"workers must be a whole number from 1 to "
+                         f"{MAX_WORKERS}, the mix says {w!r}")
+    if w > 1 and not mix["repair"]:
+        raise ValueError("workers above 1 need a repair mix")
+    return w
+
+
+@dataclasses.dataclass
+class Worker:
+    """One closed loop of a repair mix: its block group's store, its order
+    of the fixed draws and its stream of kernel sample columns.  Only its
+    own thread touches it."""
+    index: int
+    sample_rng: np.random.Generator
+    order: Optional[np.ndarray] = None
+    store: object = None
+    steps: int = 0
+    step_mm_s: float = 0.0           # GF matmul seconds of the step so far
 
 
 def _span(name: str):
@@ -61,44 +115,53 @@ class Traffic:
     def __init__(self, config: dict, mix: dict, seed: int,
                  program: Optional[Program] = None):
         self.config, self.mix, self.seed = config, mix, seed
+        self.workers = [
+            Worker(w, np.random.default_rng(_stream(seed, 1, w)))
+            for w in range(workers_of(mix))]
         self.program = program if program is not None else Program(config)
         self.n, self.k, self.d = config["n"], config["k"], config["d"]
         self.alpha, self.M = config["alpha"], config["M"]
         self.cell = config["cell_bytes"]
         self.rng = np.random.default_rng([seed, 0])
-        self.sample_rng = np.random.default_rng([seed, 1])
         self.recording = False
         self.steps = 0
-        # records of the window
+        # records of the window, appended under the lock
+        self.lock = threading.Lock()
         self.plan_calls: List[Tuple[str, int, float]] = []
         self.plan_records: List[Tuple[str, np.ndarray, object]] = []
-        self.repairs = 0
         self.repaired: List[int] = []
         self.exec_s: List[float] = []
-        self.mm_s: List[float] = []
+        # the GF matmul seconds inside each completed repair
+        self.exec_mm_s: List[float] = []
         self.mm_shapes: List[Shape] = []
         self.mm_samples: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
-        self.store = None
+        self.worker_of: Dict[str, List[int]] = {r: [] for r in RECORDS}
         self.draws = None
         self.warmed_shapes: List[Shape] = []
         self.traced: Optional["Traffic"] = None
 
     # -- the store's matmul: the program's kernel, with spans and samples --
 
-    def matmul(self, a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    def _record(self, worker: Worker, **entries) -> None:
+        with self.lock:
+            for name, entry in entries.items():
+                getattr(self, name).append(entry)
+                self.worker_of[name].append(worker.index)
+
+    def matmul(self, a: np.ndarray, b: np.ndarray,
+               worker: Worker) -> np.ndarray:
         t0 = time.perf_counter()
         with _span("bench.gf_matmul"):
             out = self.program.kernel_matmul(a, b)
         dt = time.perf_counter() - t0
         if self.recording:
-            m, k = a.shape
+            worker.step_mm_s += dt
             n = b.shape[1]
-            self.mm_s.append(dt)
-            self.mm_shapes.append((m, k, n))
             s = min(n, CHECK_COLUMNS)
-            cols = np.sort(self.sample_rng.choice(n, s, replace=False))
-            self.mm_samples.append((np.array(a, np.uint8), b[:, cols],
-                                    np.asarray(out)[:, cols]))
+            cols = np.sort(worker.sample_rng.choice(n, s, replace=False))
+            self._record(worker, mm_shapes=(*a.shape, n),
+                         mm_samples=(np.array(a, np.uint8), b[:, cols],
+                                     np.asarray(out)[:, cols]))
         return out
 
     # -- set-up -------------------------------------------------------------
@@ -115,19 +178,24 @@ class Traffic:
                 helpers = [int(x) for x in drng.permutation(
                     [j for j in range(self.n) if j != failed])[:self.d]]
                 self.draws.append((caps, failed, helpers))
-            self.order = np.random.default_rng([self.seed, 3]).permutation(
-                len(self.draws))
+            for w in self.workers:
+                w.order = np.random.default_rng(
+                    _stream(self.seed, 3, w.index)).permutation(
+                        len(self.draws))
         t0 = time.perf_counter()
         plans = self._warm_planner()
         say("setup", part="planner_warmup", seconds=time.perf_counter() - t0)
         if mix["repair"]:
             t0 = time.perf_counter()
-            with _span("bench.store_build"):
-                self.store = self.program.store(
-                    data_file(self.seed, self.M, self.cell), self.seed,
-                    self.matmul)
+            for w in self.workers:
+                with _span("bench.store_build"):
+                    w.store = self.program.store(
+                        data_file(self.seed, self.M, self.cell, w.index),
+                        store_seed(self.seed, w.index),
+                        functools.partial(self.matmul, worker=w))
             say("setup", part="store_build", seconds=time.perf_counter() - t0,
-                store_bytes=self.n * self.alpha * self.cell)
+                store_bytes=self.n * self.alpha * self.cell,
+                stores=len(self.workers))
             t0 = time.perf_counter()
             self._warm_kernel(plans)
             say("setup", part="gf_shape_warmup",
@@ -170,57 +238,71 @@ class Traffic:
             shadow.execute_plan(plan, failed, helpers)
         for m, k, n in sorted(found):
             n = self.cell if n == 1 else n
-            self.matmul(np.zeros((m, k), np.uint8), np.zeros((k, n), np.uint8))
+            self.matmul(np.zeros((m, k), np.uint8), np.zeros((k, n), np.uint8),
+                        self.workers[0])
             self.warmed_shapes.append((m, k, n))
 
     # -- one step -------------------------------------------------------------
 
-    def _plan(self, caps: np.ndarray, scheme: str):
+    def _plan(self, caps: np.ndarray, scheme: str, worker: Worker):
         t0 = time.perf_counter()
         with _span("bench.plan_many"):
             res = self.program.plan(caps, scheme)
         dt = time.perf_counter() - t0
         if self.recording:
-            self.plan_calls.append((scheme, caps.shape[0], dt))
-            self.plan_records.append((scheme, caps, res))
+            self._record(worker, plan_calls=(scheme, caps.shape[0], dt),
+                         plan_records=(scheme, caps, res))
         return res
 
-    def step(self) -> None:
+    def step(self, w: Worker) -> None:
+        """One step of worker ``w``, on its store."""
         mix = self.mix
+        w.step_mm_s = 0.0
         with _span("bench.step"):
             if self.draws is not None:
                 caps, failed, helpers = self.draws[
-                    self.order[self.steps % len(self.draws)]]
+                    w.order[w.steps % len(self.draws)]]
             else:
                 B = int(mix["batch"][self.rng.integers(len(mix["batch"]))])
                 caps = overlays(self.rng, B, self.d,
                                 self.config["capacity_lo"],
                                 self.config["capacity_hi"])
             for scheme in mix["schemes"]:
-                res = self._plan(caps, scheme)
+                res = self._plan(caps, scheme, w)
             if mix["repair"]:
                 plan = self.program.plans(res)[0]
                 t0 = time.perf_counter()
                 with _span("bench.execute_plan"):
-                    self.store.nodes.pop(failed, None)  # the node is lost
-                    self.store.execute_plan(plan, failed, helpers)
+                    w.store.nodes.pop(failed, None)  # the node is lost
+                    w.store.execute_plan(plan, failed, helpers)
                 dt = time.perf_counter() - t0
                 if self.recording:
-                    self.exec_s.append(dt)
-                    self.repairs += 1
-                    self.repaired.append(failed)
-        self.steps += 1
+                    self._record(w, exec_s=dt, exec_mm_s=w.step_mm_s,
+                                 repaired=failed)
+        w.steps += 1
+        with self.lock:
+            self.steps += 1
 
     # -- what the window did ----------------------------------------------------
 
     def mark_traced(self) -> None:
         """Keep, as ``self.traced``, what the traced part of the window did:
         a copy of the records so far, for the per-layer readers."""
-        t = copy.copy(self)
-        for name in ("plan_calls", "exec_s", "mm_s", "mm_shapes"):
-            setattr(t, name, list(getattr(self, name)))
-        t.repaired = list(self.repaired)
+        with self.lock:
+            t = copy.copy(self)
+            for name in RECORDS:
+                setattr(t, name, list(getattr(self, name)))
+            t.worker_of = {k: list(v) for k, v in self.worker_of.items()}
         self.traced = t
+
+    @property
+    def repairs(self) -> int:
+        """Repairs the window completed, over every worker."""
+        return len(self.repaired)
+
+    def repairs_by_worker(self) -> List[int]:
+        return [self.worker_of["repaired"].count(w.index)
+                for w in self.workers]
 
     def operations(self) -> int:
         return self.repairs if self.mix["repair"] else len(self.plan_calls)

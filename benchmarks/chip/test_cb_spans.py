@@ -130,7 +130,7 @@ class _Traffic:
     repairs: int = 1
     plan_calls: tuple = (("ftr", 1, 0.4e-6), ("ftr", 2, 0.3e-6))
     exec_s: tuple = (1.2e-6,)
-    mm_s: tuple = (0.4e-6,)
+    exec_mm_s: tuple = (0.4e-6,)
     mm_shapes: tuple = ((43, 128, 1 << 20), (43, 128, 1 << 20),
                         (128, 344, 1 << 20))
     mix: dict = dataclasses.field(default_factory=lambda: {"repair": True})

@@ -139,6 +139,26 @@ def test_trace_reduction_of_a_synthetic_trace():
     assert cb_trace.planner_seconds(s2) == pytest.approx(170e-9)
 
 
+def test_idle_gaps_of_overlapping_workers_go_to_the_latest_open_span():
+    # two workers' steps overlap; each gap goes to the span that started
+    # last among those open in its middle
+    ops = [_ev("gf_matmul_pallas.1", 0, 100), _ev("gf_matmul_pallas.1",
+                                                   300, 100),
+           _ev("gf_matmul_pallas.1", 500, 100), _ev("while.3", 900, 100)]
+    spans = [_ev("bench.window", 0, 1000),
+             _ev("bench.step", 0, 800),             # worker 0
+             _ev("bench.step", 50, 950),            # worker 1
+             _ev("bench.gf_matmul", 120, 100),      # worker 0, [120, 220]
+             _ev("bench.execute_plan", 420, 300)]   # worker 1, [420, 720]
+    s = cb_trace.reduce(cb_trace.Trace({0: ops}, {}, spans))
+    # [100,300]: middle 200 in worker 0's gf_matmul; [400,500] and
+    # [600,900]: middles 450 and 750, worker 1's execute_plan, then, once
+    # it has ended, worker 1's step, the later started of the two steps
+    assert s.idle_gaps == pytest.approx({"bench.gf_matmul": 200e-9,
+                                         "bench.execute_plan": 100e-9,
+                                         "bench.step": 300e-9})
+
+
 def test_trace_names_and_programs():
     assert cb_trace.op_name("%gf_matmul_pallas.1 = u8[128,1048576]{1,0} "
                             "custom-call(u8[128,512] %pad.0)") == \
