@@ -62,12 +62,16 @@ class RLNC:
     def distribute(self, file_blocks: np.ndarray, n: int, alpha: int,
                    rng: np.random.Generator) -> List[CodedBlocks]:
         """Encode M file blocks into n nodes * alpha coded blocks (random
-        linear code; MDS with probability -> 1 for large fields)."""
+        linear code; MDS with probability -> 1 for large fields).
+
+        The block group is encoded in one matmul; each node's rows are then
+        copied out of the product into arrays of their own, so a node that
+        is dropped frees its rows."""
         M = file_blocks.shape[0]
         C = self.field.random((n * alpha, M), rng)
         payload = self._matmul(C, file_blocks)
-        return [CodedBlocks(C[i * alpha:(i + 1) * alpha],
-                            payload[i * alpha:(i + 1) * alpha])
+        return [CodedBlocks(C[i * alpha:(i + 1) * alpha].copy(),
+                            payload[i * alpha:(i + 1) * alpha].copy())
                 for i in range(n)]
 
     # -- regeneration --------------------------------------------------------

@@ -1,7 +1,8 @@
 """Named spans on the profiler's own clock.
 
 ``span(name, **args)`` is a ``jax.profiler.TraceAnnotation`` called
-``"repro." + name``; its numeric ``args`` become stats of the trace event.
+``"repro." + name``; its numeric ``args`` become stats of the trace event,
+and so do those given to ``set_metadata`` on the entered span.
 While no profiler runs, entering one records nothing and costs about a
 microsecond, so the spans stay in the code with no switch.  A trace taken
 with ``jax.profiler.trace(dir)`` then puts the program's host steps on the
@@ -9,13 +10,29 @@ same clock as the device's operations (``src/README.md``, "Spans").
 
 This module is the one part of ``repro.obs`` that ``repro.core`` imports.
 JAX is imported on the first span, so importing the planners stays cheap;
-without JAX a span is an empty context.
+without JAX a span is an empty context.  Threads may open spans at once:
+the first spans of several threads may each look JAX up, and bind the
+same class.
 """
 from __future__ import annotations
 
-import contextlib
-
 _annotation = None
+
+
+class _NoSpan:
+    """A span where JAX is missing: it records nothing."""
+
+    def __init__(self, name: str, **args):
+        pass
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **args) -> None:
+        pass
 
 
 def span(name: str, **args):
@@ -25,6 +42,5 @@ def span(name: str, **args):
         try:
             from jax.profiler import TraceAnnotation as _annotation
         except ImportError:
-            def _annotation(name, **args):
-                return contextlib.nullcontext()
+            _annotation = _NoSpan
     return _annotation("repro." + name, **args)

@@ -16,6 +16,7 @@ import dataclasses
 import itertools
 import math
 import random
+import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -113,8 +114,31 @@ def compare_schemes(params: CodeParams, sampler: CapSampler,
 # Data-plane simulation with real coding vectors (Fig. 10)
 # ---------------------------------------------------------------------------
 
+@dataclasses.dataclass(frozen=True)
+class Reconstruction:
+    """One ``execute_plan`` of a store: its start and end on
+    ``time.perf_counter``, the coded rows the newcomer received and the
+    payload bytes the repair gave the GF matmul as operands."""
+    failed: int
+    start_s: float
+    end_s: float
+    rows_received: int
+    payload_bytes: int
+
+
+def _owner(a: np.ndarray) -> np.ndarray:
+    """The array that owns the memory ``a`` views."""
+    while isinstance(a.base, np.ndarray):
+        a = a.base
+    return a
+
+
 class RlncSimulator:
-    """Distributed storage system with actual RLNC state per node."""
+    """Distributed storage system with actual RLNC state per node.
+
+    ``reconstructions`` counts the store's repairs, one
+    :class:`Reconstruction` per ``execute_plan``; a store belongs to one
+    thread at a time, so the list takes no lock."""
 
     def __init__(self, params: CodeParams, field: GF = GF8,
                  block_bytes: int = 4, seed: int = 0,
@@ -135,6 +159,17 @@ class RlncSimulator:
         self.nodes: Dict[int, CodedBlocks] = dict(
             enumerate(self.rl.distribute(self.file_blocks, n, alpha,
                                          self.np_rng)))
+        self.reconstructions: List[Reconstruction] = []
+
+    def host_bytes(self) -> int:
+        """Host bytes the nodes' rows hold: every array that owns memory
+        under their coding vectors and payloads, counted once and whole."""
+        owners = {}
+        for blocks in self.nodes.values():
+            for a in (blocks.vectors, blocks.payload):
+                o = _owner(a)
+                owners[id(o)] = o.nbytes
+        return sum(owners.values())
 
     def execute_plan(self, plan: RepairPlan, failed: int,
                      provider_ids: Sequence[int]) -> None:
@@ -149,17 +184,34 @@ class RlncSimulator:
         relaying node and at the newcomer.  A pool within its edge flow
         goes up as it is.
 
-        Spans: ``repro.execute_plan`` around the call,
-        ``repro.store.node`` around each tree node's work, the newcomer's
-        included (a node's span holds those of its children), and
-        ``repro.store.concat`` around each join.
+        Spans: ``repro.execute_plan`` around the call, with args ``rows``
+        (coded rows the newcomer received) and ``payload_bytes`` (payload
+        bytes given to the GF matmul), ``repro.store.node`` around each
+        tree node's work, the newcomer's included (a node's span holds
+        those of its children), and ``repro.store.concat`` around each
+        join.  The same numbers, with the call's start and end, are
+        appended to ``reconstructions``.
         """
-        with span("execute_plan"), span("store.node"):
-            self._execute_plan(plan, failed, provider_ids)
+        start = time.perf_counter()
+        with span("execute_plan") as s, span("store.node"):
+            rows, payload_bytes = self._execute_plan(plan, failed,
+                                                     provider_ids)
+            s.set_metadata(rows=rows, payload_bytes=payload_bytes)
+        self.reconstructions.append(Reconstruction(
+            failed, start, time.perf_counter(), rows, payload_bytes))
 
     def _execute_plan(self, plan: RepairPlan, failed: int,
-                      provider_ids: Sequence[int]) -> None:
+                      provider_ids: Sequence[int]) -> tuple[int, int]:
+        """The repair; returns the rows the newcomer received and the
+        payload bytes given to the matmul."""
         alpha = int(round(self.params.alpha))
+        payload_bytes = 0
+
+        def encode(blocks: CodedBlocks, num_out: int) -> CodedBlocks:
+            nonlocal payload_bytes
+            payload_bytes += blocks.payload.nbytes
+            return self.rl.encode(blocks, num_out, self.np_rng)
+
         idmap = {i: pid for i, pid in enumerate(provider_ids, start=1)}
         children: Dict[int, List[int]] = {}
         for u, p in plan.parent.items():
@@ -173,9 +225,8 @@ class RlncSimulator:
                 with span("store.node"):
                     pool += produce(ch)
             send_quota = int(math.ceil(plan.flows[(u, plan.parent[u])] - 1e-9))
-            own = self.rl.encode(self.nodes[idmap[u]],
-                                 int(math.ceil(plan.betas[u - 1] - 1e-9)),
-                                 self.np_rng)
+            own = encode(self.nodes[idmap[u]],
+                         int(math.ceil(plan.betas[u - 1] - 1e-9)))
             if not pool:
                 # a leaf: cap at the plan's edge flow (RCTREE keeps this
                 # below alpha)
@@ -185,16 +236,17 @@ class RlncSimulator:
             if sum(p.num for p in pool) <= send_quota:
                 return pool                # forwarded unjoined, within the flow
             # relay: recode the pool, joined once, down to the edge flow
-            return [self.rl.encode(CodedBlocks.join(pool), send_quota,
-                                   self.np_rng)]
+            return [encode(CodedBlocks.join(pool), send_quota)]
 
         received: List[CodedBlocks] = []
         for r in children.get(0, []):
             with span("store.node"):
                 received += produce(r)
         assert received
-        self.nodes[failed] = self.rl.regenerate(CodedBlocks.join(received),
-                                                alpha, self.np_rng)
+        pool = CodedBlocks.join(received)
+        payload_bytes += pool.payload.nbytes
+        self.nodes[failed] = self.rl.regenerate(pool, alpha, self.np_rng)
+        return pool.num, payload_bytes
 
     def _sample_round(self, sampler: CapSampler,
                       failed: Optional[int] = None):

@@ -146,6 +146,19 @@ def test_copy_bytes_are_the_operands(traced):
     assert "repro.store.node" not in args
 
 
+def test_execute_plan_reports_rows_and_payload_bytes(traced):
+    trace, _, (_, sim, _, _), shapes, _, _ = traced
+    (ex,) = _named(trace, "repro.execute_plan")
+    (rec,) = sim.reconstructions
+    # payload operands are 16 bytes wide, coding-vector operands M = 4;
+    # the newcomer's regenerate, the last call, reads every row it received
+    payload = sum(k * n for _, k, n in shapes if n == 16)
+    assert ex.args == {"rows": rec.rows_received,
+                       "payload_bytes": rec.payload_bytes}
+    assert rec.payload_bytes == payload and shapes[-1][1] == rec.rows_received
+    assert rec.start_s < rec.end_s
+
+
 def test_spans_change_no_result(traced):
     _, (res0, sim0, c10, c20), (res1, sim1, c11, c21), _, _, _ = traced
     for f in ("times", "traffic", "betas", "parents", "lower_bounds"):

@@ -25,6 +25,7 @@ from repro.kernels.gf_matmul import gf_matmul_pallas
 from repro.kernels.ops import _padded_call
 
 PAYLOAD = 1 << 20        # one 1 MiB HDFS striping cell per coded block
+WIDE_ROW = 2 << 20       # a 2 MiB row: 128 rows per 256 MB warehouse block
 
 
 @pytest.fixture(scope="module")
@@ -75,6 +76,20 @@ def test_gf_kernel_compiles_at_repair_payload(one_chip, m, k):
     assert _has_kernel(compiled)
     out = compiled.out_info
     assert out.shape == (128, PAYLOAD) and out.dtype == jnp.uint8
+
+
+@pytest.mark.parametrize("m,k", [(14 * 128, 1280), (128, 13 * 32)],
+                         ids=["set_up_encode", "newcomer_regenerate"])
+def test_gf_kernel_compiles_at_warehouse_rows(one_chip, m, k):
+    """The widest products of the RS(14,10) store on 2 MiB rows: the encode
+    of a 1280-row block group into 14 nodes' 128 rows, and the newcomer's
+    128 combinations of the 13 helpers' 32 rows each (MSR)."""
+    a = _spec((m, k), jnp.uint8, one_chip)
+    b = _spec((k, WIDE_ROW), jnp.uint8, one_chip)
+    compiled = _padded_call.lower(a, b, 128, 128, 512, False).compile()
+    assert _has_kernel(compiled)
+    out = compiled.out_info
+    assert out.shape == (m, WIDE_ROW) and out.dtype == jnp.uint8
 
 
 def test_fr_planner_compiles_under_x64(one_chip):
